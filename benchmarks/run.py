@@ -31,6 +31,7 @@ from benchmarks import (
     micro_scheduler,
     table1_accuracy,
 )
+from repro.runtime.compilation import enable_compile_cache
 
 MODULES = {
     "fig2": fig2_profile,
@@ -56,6 +57,7 @@ MODULES = {
 
 def main() -> None:
     wanted = sys.argv[1:] or list(MODULES)
+    enable_compile_cache()
     print("name,us_per_call,derived")
     t0 = time.time()
     for key in wanted:

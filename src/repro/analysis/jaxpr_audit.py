@@ -124,9 +124,8 @@ def audit_artifact(spec) -> List[Finding]:
     """Trace one manifest :class:`~repro.analysis.manifest.ArtifactSpec`
     and audit the resulting jaxpr."""
     import jax
-    from jax.experimental import enable_x64
 
-    ctx = enable_x64() if spec.x64 else contextlib.nullcontext()
+    ctx = jax.enable_x64(True) if spec.x64 else contextlib.nullcontext()
     with ctx:
         fn, args, kwargs = spec.build()
         try:
@@ -182,9 +181,9 @@ def no_recompile_findings(guard) -> List[Finding]:
     """
     import contextlib as _ctx
 
-    from jax.experimental import enable_x64
+    import jax
 
-    ctx = enable_x64() if getattr(guard, "x64", False) else (
+    ctx = jax.enable_x64(True) if getattr(guard, "x64", False) else (
         _ctx.nullcontext())
     with ctx:
         fn, calls = guard.build()

@@ -54,7 +54,7 @@ class ArtifactSpec:
     """A compiled artifact the jaxpr auditor traces.
 
     ``build()`` returns ``(fn, args, kwargs)``; the auditor runs
-    ``jax.make_jaxpr(fn)(*args, **kwargs)`` (under ``enable_x64`` when
+    ``jax.make_jaxpr(fn)(*args, **kwargs)`` (under ``jax.enable_x64(True)`` when
     ``x64``) and checks the dtype contract + primitive denylist.
     ``rtol`` is the declared kernel-vs-float64-reference error bound for
     ``float32``-contract artifacts (enforced by the tolerance test).
@@ -79,7 +79,7 @@ class RecompileGuard:
 
     name: str
     build: Callable[[], Tuple[Any, list]]
-    x64: bool = False       # run the sweep under enable_x64
+    x64: bool = False       # run the sweep under jax.enable_x64(True)
     notes: str = ""
 
 
@@ -393,8 +393,8 @@ def _guard_jnp_score():
 
 
 def _guard_scan_chunk():
+    import jax
     import numpy as np
-    from jax.experimental import enable_x64
     from repro.core.simfast import _build_chunk_fn
 
     key = _scan_chunk_key()
@@ -402,7 +402,7 @@ def _guard_scan_chunk():
     lanes, m, e, p = 2, key.num_models, key.num_exits, key.pad_len
     b1, r = key.max_batch + 1, len(key.ladder[0])
     rng = np.random.default_rng(43)
-    with enable_x64():
+    with jax.enable_x64(True):
         calls = []
         for tau in (0.05, 0.08, 0.12):
             for limit in (1.0, 2.0):

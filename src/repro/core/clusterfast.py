@@ -94,7 +94,6 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental import enable_x64
 
 from repro.core.baselines import make_scheduler
 from repro.core.cluster import (
@@ -871,7 +870,7 @@ def simulate_cluster_scan_batch(
                     drain_tab[d, m, q] = drain_cell(s, m, q)
         parse = [_LaneParse(G, M) for _ in lanes]
         overflowed = False
-        with enable_x64():
+        with jax.enable_x64(True):
             shared = (
                 jnp.asarray(lat_by_cap), jnp.asarray(exec_lat),
                 jnp.asarray(drain_tab), jnp.asarray(b1_final),

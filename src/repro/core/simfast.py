@@ -26,7 +26,7 @@ refactors a whole serving run into fixed-shape array state so it compiles:
     ``jax.vmap`` lays independent traces (seeds x rates) side by side and
     ``jit`` compiles the whole run.
 
-Everything runs in float64 (``jax.experimental.enable_x64``): the clock
+Everything runs in float64 (``jax.enable_x64(True)``): the clock
 evolves by the *identical* IEEE operations as the Python loop (``t + L``,
 ``nextafter``), so dispatch/finish timestamps are bitwise-equal and
 decisions stay equivalent — stability scores differ only at the ~ulp level
@@ -71,7 +71,6 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental import enable_x64
 
 from repro.core.baselines import AllFinalDeadlineAwareScheduler, NoBatchingScheduler
 from repro.core.metrics import summarize_arrays
@@ -723,7 +722,7 @@ def simulate_scan_batch(
         )
         chunk_fn = _build_chunk_fn(key)
         arr = _pack_lanes(lanes, M, P, factored)
-        with enable_x64():
+        with jax.enable_x64(True):
             L = len(lanes)
             carry = (
                 jnp.zeros(L, dtype=jnp.float64),

@@ -12,7 +12,9 @@ to serial**:
     order could depend on scheduling);
   * results are returned in grid order regardless of completion order;
   * workers are plain ``ProcessPoolExecutor`` processes using the ``spawn``
-    start method (fork-safety: the parent may hold live JAX/XLA threads).
+    start method (fork-safety: the parent may hold live JAX/XLA threads),
+    each pinned to JAX's CPU backend before it runs a cell, since the
+    parent may hold the accelerator and a chip serves one process.
 
 ``ServingMetrics`` is a frozen dataclass of floats/ints/tuples, so
 "bitwise-identical" is checked with plain ``==`` (asserted in
@@ -152,6 +154,15 @@ class SweepResult:
 def _run_cell(runner: "SweepRunner", spec: SweepSpec) -> SweepResult:
     """Module-level trampoline so the pool can pickle the call."""
     return runner.run_cell(spec)
+
+
+def _pin_worker_to_cpu() -> None:
+    """Pool initializer: sweep cells are host simulation, so a worker never
+    reaches for the accelerator that its parent may hold (one process per
+    chip)."""
+    import jax
+
+    jax.config.update("jax_platforms", "cpu")
 
 
 class SweepRunner:
@@ -408,7 +419,8 @@ class SweepRunner:
         # whose locks a forked child would inherit mid-flight.
         ctx = multiprocessing.get_context("spawn")
         with concurrent.futures.ProcessPoolExecutor(
-            max_workers=workers, mp_context=ctx
+            max_workers=workers, mp_context=ctx,
+            initializer=_pin_worker_to_cpu,
         ) as pool:
             futures = [pool.submit(_run_cell, self, s) for s in specs]
             return [f.result() for f in futures]

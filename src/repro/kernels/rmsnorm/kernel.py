@@ -2,7 +2,9 @@
 
 grid = (T/bt,); block [bt, D] with the full feature dim resident so the
 mean-square reduction is a single VMEM pass; fp32 accumulation, output in
-the input dtype. D up to 8k at bt=256 is ~8 MB fp32 — inside v5e VMEM.
+the input dtype. Input and output blocks are double-buffered, so fp32
+needs 16 * bt * D bytes of VMEM: 8 MiB at bt=128, D=4096, inside v5e's
+16 MiB scoped limit (bt=256 exceeds it).
 """
 
 from __future__ import annotations
@@ -23,7 +25,7 @@ def _rmsnorm_kernel(x_ref, g_ref, o_ref, *, eps: float):
         o_ref.dtype)
 
 
-def rmsnorm_kernel(x, gain, *, eps: float = 1e-6, block_t: int = 256,
+def rmsnorm_kernel(x, gain, *, eps: float = 1e-6, block_t: int = 128,
                    interpret: bool = False):
     """x [T, D]; gain [D] -> [T, D]."""
     t, d = x.shape

@@ -12,7 +12,7 @@ from repro.kernels.rmsnorm.ref import rmsnorm_ref
 
 @functools.partial(jax.jit, static_argnames=("eps", "block_t", "interpret",
                                              "use_kernel"))
-def rmsnorm(x, gain, *, eps: float = 1e-6, block_t: int = 256,
+def rmsnorm(x, gain, *, eps: float = 1e-6, block_t: int = 128,
             interpret: bool = False, use_kernel: bool = True):
     """RMSNorm over the last dim of a 2D input."""
     if not use_kernel:

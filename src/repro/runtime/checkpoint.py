@@ -150,8 +150,8 @@ class Checkpointer:
           step: specific step (default: latest committed).
           shardings: optional NamedSharding tree — arrays are device_put
             against it (resharding onto the current mesh).
-          template: optional pytree with the expected structure; used to
-            validate the manifest structure matches.
+          template: pytree with the expected structure (required); the
+            loaded leaves are unflattened into it.
         Returns (step, tree, extra).
         """
         if step is None:
@@ -163,20 +163,14 @@ class Checkpointer:
             raise FileNotFoundError(f"checkpoint step {step} not committed")
         with open(os.path.join(d, "manifest.json")) as f:
             manifest = json.load(f)
-        treedef = jax.tree_util.tree_structure_from_proto_bytes(
-            bytes.fromhex(manifest["treedef"])
-        ) if hasattr(jax.tree_util, "tree_structure_from_proto_bytes") else None
+        if template is None:
+            raise ValueError("restore requires a template pytree")
         leaves = [
             np.load(os.path.join(d, f"leaf_{i:05d}.npy"))
             for i in range(manifest["num_leaves"])
         ]
-        if template is not None:
-            _, expect_def = jax.tree.flatten(template)
-            tree = jax.tree.unflatten(expect_def, leaves)
-        elif treedef is not None:
-            tree = jax.tree.unflatten(treedef, leaves)
-        else:
-            raise ValueError("restore requires a template pytree")
+        _, expect_def = jax.tree.flatten(template)
+        tree = jax.tree.unflatten(expect_def, leaves)
         if shardings is not None:
             tree = jax.tree.map(
                 lambda x, s: jax.device_put(x, s), tree, shardings)

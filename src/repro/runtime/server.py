@@ -8,7 +8,9 @@ profile table.
 
 Offline phase  = ``measure_profile`` (wall-clock profile of every
 (m, e, B) — one compiled executable per cell, exactly the paper's 120-cell
-table), then ``ServingEngine.run`` is the online phase. With an
+table), then ``ServingEngine.run`` is the online phase. Both run a cell
+through ``ServedModel.execute``, whose executable cache they share, so a
+deployment compiles each (m, e, B) once per process. With an
 ``OnlineProfiler`` attached (``repro.core.adaptive``), the offline table is
 only the *cold start*: measured wall-clock service times feed back into
 refreshed scheduler tables while serving, tracking device drift (thermal
@@ -23,7 +25,6 @@ import time
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from repro.core.adaptive import OnlineProfiler
@@ -48,6 +49,8 @@ class ServedModel:
       data_fn:    ``(batch_size) -> input payload batch`` for profiling and
                   serving quanta.
       num_exits:  number of early-exit heads, shallowest -> deepest.
+      executables: ``(exit, batch) -> compiled executable``, filled on
+                  first use by :meth:`execute`.
     """
 
     name: str
@@ -55,6 +58,24 @@ class ServedModel:
     forward_fn: Callable[[Any, Any, int], Any]
     data_fn: Callable[[int], Any]
     num_exits: int
+    executables: Dict[Tuple[int, int], Any] = dataclasses.field(
+        default_factory=dict, init=False, repr=False, compare=False)
+
+    def execute(self, e: int, b: int):
+        """One quantum: exit ``e`` at batch ``b``, blocked until done.
+
+        The first call for an (e, b) cell compiles it ahead of time; later
+        calls reuse that executable, which refuses inputs of another shape
+        rather than recompiling behind the caller's back.
+        """
+        x = self.data_fn(b)
+        fn = self.executables.get((e, b))
+        if fn is None:
+            fn = jax.jit(
+                lambda v, x, _e=e: self.forward_fn(v, x, _e)
+            ).lower(self.values, x).compile()
+            self.executables[(e, b)] = fn
+        return jax.block_until_ready(fn(self.values, x))
 
 
 def measure_profile(
@@ -68,26 +89,17 @@ def measure_profile(
 ) -> ProfileTable:
     """Offline profiling phase (paper Sec. IV-B) against the live device.
 
-    Compiles one executable per (m, e, B) cell and records the
-    ``percentile`` wall-clock latency over ``repeats`` runs after ``warmup``
-    discarded runs (``ProfileTable.measure`` underneath) — the paper's
-    120-cell table, measured rather than calibrated. The result is the
+    Compiles one executable per (m, e, B) cell (kept on the
+    :class:`ServedModel` for ``ServingEngine.warmup`` to reuse) and records
+    the ``percentile`` wall-clock latency over ``repeats`` runs after
+    ``warmup`` discarded runs (``ProfileTable.measure`` underneath) — the
+    paper's 120-cell table, measured rather than calibrated. The result is the
     scheduler's *cold-start* belief; attach an
     ``repro.core.adaptive.OnlineProfiler`` to :class:`ServingEngine` to keep
     it tracking the device online (docs/runtime.md "Online adaptation").
     """
-    compiled: Dict[Tuple[int, int, int], Callable] = {}
-
     def run_fn(m: int, e: int, b: int):
-        key = (m, e, b)
-        if key not in compiled:
-            mod = models[m]
-            fn = jax.jit(
-                lambda v, x, _e=e, _mod=mod: _mod.forward_fn(v, x, _e))
-            compiled[key] = fn
-        mod = models[m]
-        out = compiled[key](mod.values, mod.data_fn(b))
-        jax.block_until_ready(out)
+        models[m].execute(e, b)
 
     n_exits = models[0].num_exits
     return ProfileTable.measure(
@@ -134,7 +146,6 @@ class ServingEngine:
         self.queues = [ServiceQueue(m) for m in range(len(models))]
         self.completions: List[Completion] = []
         self.dropped = 0
-        self._compiled: Dict[Tuple[int, int, int], Callable] = {}
         self._busy_s = 0.0
         self._unsubmitted = 0  # trace tail never ingested (drain-cap exit)
         # Structured engine counters, cumulative across run() calls (like
@@ -158,19 +169,11 @@ class ServingEngine:
 
     # -- execution ---------------------------------------------------------------
 
-    def _execute(self, m: int, e: int, b: int):
-        key = (m, e, b)
-        if key not in self._compiled:
-            mod = self.models[m]
-            self._compiled[key] = jax.jit(
-                lambda v, x, _e=e, _mod=mod: _mod.forward_fn(v, x, _e))
-        mod = self.models[m]
-        out = self._compiled[key](mod.values, mod.data_fn(b))
-        jax.block_until_ready(out)
-        return out
-
     def warmup(self, batch_sizes: Optional[Sequence[int]] = None) -> None:
         """Pre-compile every (m, e, B) so online serving never JITs.
+
+        Cells that ``measure_profile`` already ran are reused, not
+        compiled again (the cache lives on each :class:`ServedModel`).
 
         ``batch_sizes=None`` derives the reachable batch set from the
         scheduler itself: the union of its candidate ladders over every
@@ -183,10 +186,10 @@ class ServingEngine:
             for qlen in range(1, self.scheduler.config.max_batch + 1):
                 reach.update(self.scheduler.batch_candidates(qlen))
             batch_sizes = sorted(reach)
-        for m, mod in enumerate(self.models):
+        for mod in self.models:
             for e in range(mod.num_exits):
                 for b in batch_sizes:
-                    self._execute(m, e, b)
+                    mod.execute(e, b)
 
     def run(
         self,
@@ -253,8 +256,8 @@ class ServingEngine:
                 continue
             batch = self.queues[decision.model].pop_batch(decision.batch_size)
             t_dispatch = self.clock() - t0
-            self._execute(decision.model, decision.exit_idx,
-                          decision.batch_size)
+            self.models[decision.model].execute(decision.exit_idx,
+                                                decision.batch_size)
             t_done = self.clock() - t0
             self._busy_s += t_done - t_dispatch
             self.counters["batches_served"] += 1

@@ -96,16 +96,10 @@ class TestCollectiveParsing:
     def test_psum_counted(self):
         # shard_map psum over 1 device still emits an all-reduce op.
         from jax.sharding import PartitionSpec as P
-        if hasattr(jax.sharding, "AxisType"):  # jax >= 0.5
-            mesh = jax.make_mesh((1,), ("x",),
-                                 axis_types=(jax.sharding.AxisType.Auto,))
-        else:
-            mesh = jax.make_mesh((1,), ("x",))
-        shard_map = getattr(jax, "shard_map", None)
-        if shard_map is None:  # jax < 0.5
-            from jax.experimental.shard_map import shard_map
+        mesh = jax.make_mesh((1,), ("x",),
+                             axis_types=(jax.sharding.AxisType.Auto,))
         f = jax.jit(
-            shard_map(
+            jax.shard_map(
                 lambda x: jax.lax.psum(x, "x"), mesh=mesh,
                 in_specs=P("x"), out_specs=P()))
         txt = f.lower(jnp.zeros((8, 128))).compile().as_text()

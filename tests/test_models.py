@@ -111,8 +111,6 @@ class TestFamilies:
                 lambda v, b: model.forward_exit(v, b, e)
             ).lower(values, batch).compile()
             ca = c.cost_analysis()
-            if isinstance(ca, (list, tuple)):  # jax < 0.5 returns [dict]
-                ca = ca[0] if ca else {}
             return ca.get("flops", 0.0)
 
         assert flops(0) < flops(cfg.num_exits - 1)
@@ -252,8 +250,6 @@ class TestResNet:
             ca = jax.jit(
                 lambda v, x: model.forward_exit(v, x, e)
             ).lower(values, imgs).compile().cost_analysis()
-            if isinstance(ca, (list, tuple)):  # jax < 0.5 returns [dict]
-                ca = ca[0] if ca else {}
             return ca.get("flops", 0.0)
 
         f = [flops(e) for e in range(4)]

@@ -155,6 +155,66 @@ class TestEngineDrainCap:
         assert len(completions) == 4
 
 
+class TestResNetTrioDeployment:
+    """The paper's trio (SMOKE widths) through measure_profile ->
+    EdgeServingScheduler -> ServingEngine.warmup -> ServingEngine.run."""
+
+    BATCHES = (1, 2)
+
+    @pytest.fixture(scope="class")
+    def served(self):
+        from repro.configs.edgeserving_resnets import SMOKE
+        from repro.core import poisson_arrivals
+        from repro.runtime.compilation import CompileCounter
+        from repro.runtime.resnets import served_resnets
+
+        models = served_resnets(SMOKE, seed=0)
+        table = measure_profile(models, batch_sizes=self.BATCHES,
+                                repeats=2, warmup=1)
+        profiled = [dict(m.executables) for m in models]
+        cfg = SchedulerConfig(slo=10.0, max_batch=max(self.BATCHES))
+        engine = ServingEngine(models, EdgeServingScheduler(table, cfg))
+        with CompileCounter() as warm:
+            engine.warmup()
+        arrivals = poisson_arrivals([60.0, 40.0, 20.0], 0.2, seed=1)
+        with CompileCounter() as window:
+            _, span = engine.run(arrivals, duration=0.2, drain=True)
+        return dict(models=models, table=table, profiled=profiled,
+                    engine=engine, arrivals=arrivals, span=span,
+                    warm=warm.count, window=window.count)
+
+    def test_served_counts_add_up(self, served):
+        m = served["engine"].metrics(served["table"], slo=10.0,
+                                     span=served["span"])
+        assert m.num_completed > 0
+        assert (m.num_completed + m.dropped + m.residual_queue
+                == len(served["arrivals"]))
+
+    def test_no_executable_created_while_serving(self, served):
+        assert served["window"] == 0
+
+    def test_each_cell_compiled_once(self, served):
+        # measure_profile built every (m, e, B); warmup() reused them all
+        for mod, before in zip(served["models"], served["profiled"]):
+            assert set(before) == {(e, b) for e in range(mod.num_exits)
+                                   for b in self.BATCHES}
+            assert mod.executables == before
+        assert served["warm"] == 0
+
+    def test_inputs_are_seeded_cifar_batches(self, served):
+        from repro.configs.edgeserving_resnets import SMOKE
+        from repro.runtime.resnets import served_resnets
+
+        mod = served["models"][0]
+        x = mod.data_fn(2)
+        assert x.shape == (2, 32, 32, 3) and x.dtype == jnp.float32
+        assert float(jnp.std(x)) > 0.5
+        assert mod.data_fn(2) is x          # made once, kept on the device
+        again = served_resnets({"resnet50": SMOKE["resnet50"]}, seed=0)[0]
+        np.testing.assert_array_equal(np.asarray(again.data_fn(2)),
+                                      np.asarray(x))
+
+
 class TestTrainingIntegration:
     def test_loss_decreases_tiny_lm(self):
         cfg = get_config("smollm-135m", smoke=True)
