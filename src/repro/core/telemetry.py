@@ -6,7 +6,7 @@ queue state, produced a violation spike. This module adds a record-only
 :class:`Tracer` threaded through every serving engine (the Python
 ``ServingSimulator``, the ``ClusterSimulator``, the compiled
 ``repro.core.simfast`` scan engine, and the live
-``repro.runtime.server.ServingEngine``), capturing three record kinds:
+``repro.runtime.server.ServingEngine``), capturing four record kinds:
 
   * :class:`DecisionRecord` — one per dispatched quantum: time, device, the
     chosen (model, exit, batch), the winning stability score and the
@@ -21,6 +21,9 @@ queue state, produced a violation spike. This module adds a record-only
     Symphony shedding, ``OnlineProfiler`` table refreshes,
     ``SafetyController`` multiplier changes, scan-engine overflow retries,
     live-engine counters.
+  * :class:`PhaseSpan` — live engine only: the host loop's phases,
+    contiguous spans that tile each ``run()`` from its start to its exit,
+    so a phase's self time is its duration.
 
 Tracing is **off by default and zero-cost when off**: every producer guards
 on ``tracer is not None``, the tracer only ever *appends to Python lists*
@@ -44,7 +47,7 @@ from __future__ import annotations
 import dataclasses
 import json
 import math
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, NamedTuple, Optional, Tuple
 
 import numpy as np
 
@@ -64,6 +67,7 @@ from repro.core.scheduler import (
 __all__ = [
     "DecisionRecord",
     "EVENT_KINDS",
+    "PhaseSpan",
     "RequestSpan",
     "TimelineMetrics",
     "Trace",
@@ -165,6 +169,33 @@ class TraceEvent:
         return dict(self.payload)
 
 
+class PhaseSpan(NamedTuple):
+    """One phase of the live engine's host loop, on the engine's clock.
+
+    A dispatching round runs ``ingest``, ``snapshot``, ``prune``,
+    ``decide``, ``pop``, ``input``, ``launch``, ``wait``, ``record`` and
+    ``trace``; ``input``/``launch``/``wait`` split
+    ``ServedModel.execute`` (data, the executable's call until it
+    returns, ``block_until_ready``), a ``compile`` before ``launch`` marks
+    an executable-cache miss, ``record`` is the completion log and the
+    online profiler, and ``trace`` is the tracer's own records of the
+    quantum. A run of consecutive rounds that dispatch nothing, the exit
+    round included, is one ``poll``.
+
+    ``round`` is the loop iteration the phase belongs to (for ``poll``,
+    the first idle round it covers); ``quantum`` indexes
+    ``Trace.decisions`` (-1 for ``poll``). A tuple rather than a frozen
+    dataclass: a traced round records ten or more of them, and this is the
+    cheapest immutable record.
+    """
+
+    round: int
+    quantum: int
+    name: str
+    start: float
+    end: float
+
+
 @dataclasses.dataclass(frozen=True)
 class Trace:
     """A frozen telemetry timeline (what ``Tracer.freeze`` returns and what
@@ -174,6 +205,7 @@ class Trace:
     spans: Tuple[RequestSpan, ...]
     events: Tuple[TraceEvent, ...]
     meta: Dict[str, object] = dataclasses.field(default_factory=dict)
+    phases: Tuple[PhaseSpan, ...] = ()
 
     def span_counts(self) -> Dict[str, int]:
         """``{status: count}`` over the spans (conservation check helper)."""
@@ -223,12 +255,16 @@ class Tracer:
         self.decisions: List[DecisionRecord] = []
         self.spans: List[RequestSpan] = []
         self.events: List[TraceEvent] = []
+        self.phases: List[PhaseSpan] = []
+        self._open_phase: Optional[Tuple[int, int, str, float]] = None
         self._safety_mult: Dict[int, float] = {}  # last seen, per device
 
     def reset(self) -> None:
         self.decisions.clear()
         self.spans.clear()
         self.events.clear()
+        self.phases.clear()
+        self._open_phase = None
         self._safety_mult.clear()
 
     # -- producers -----------------------------------------------------------
@@ -311,6 +347,25 @@ class Tracer:
                                   previous=last, multiplier=mult)
             self._safety_mult[device] = mult
 
+    def record_phase(self, t: float, round: int, quantum: int,
+                     name: str) -> None:
+        """Phase ``name`` of loop iteration ``round`` starts at ``t``; the
+        phase open until now ends there, so one clock read is both
+        boundaries and the phases tile the run. A ``poll`` that follows a
+        ``poll`` extends it: consecutive idle rounds make one span."""
+        open_ = self._open_phase
+        if open_ is not None:
+            if name == "poll" and open_[2] == "poll":
+                return
+            self.phases.append(PhaseSpan(*open_, t))
+        self._open_phase = (round, quantum, name, t)
+
+    def close_phase(self, t: float) -> None:
+        """End the open phase at ``t`` (the engine's exit)."""
+        if self._open_phase is not None:
+            self.phases.append(PhaseSpan(*self._open_phase, t))
+            self._open_phase = None
+
     # -- finalisation --------------------------------------------------------
 
     def freeze(self, **meta) -> Trace:
@@ -324,6 +379,7 @@ class Tracer:
             spans=tuple(self.spans),
             events=tuple(self.events),
             meta=meta,
+            phases=tuple(self.phases),
         )
 
 
@@ -550,6 +606,9 @@ def export_ndjson(trace: Trace, path: str) -> str:
                 "payload": {k: _enc(v) for k, v in e.payload},
             }, f)
             f.write("\n")
+        for p in trace.phases:
+            json.dump({"type": "phase", **p._asdict()}, f)
+            f.write("\n")
     return path
 
 
@@ -558,6 +617,7 @@ def load_ndjson(path: str) -> Trace:
     decisions: List[DecisionRecord] = []
     spans: List[RequestSpan] = []
     events: List[TraceEvent] = []
+    phases: List[PhaseSpan] = []
     meta: Dict[str, object] = {}
     with open(path) as f:
         for line in f:
@@ -590,10 +650,12 @@ def load_ndjson(path: str) -> Trace:
                     t=_dec(d["t"]), kind=d["kind"], device=d["device"],
                     payload=tuple(d["payload"].items()),
                 ))
+            elif kind == "phase":
+                phases.append(PhaseSpan(**d))
             else:
                 raise ValueError(f"unknown NDJSON record type {kind!r}")
     return Trace(decisions=tuple(decisions), spans=tuple(spans),
-                 events=tuple(events), meta=meta)
+                 events=tuple(events), meta=meta, phases=tuple(phases))
 
 
 def _chrome_args(d: Dict[str, object]) -> Dict[str, object]:
@@ -613,7 +675,9 @@ def export_chrome_trace(trace: Trace, path: str) -> str:
     lifecycles as async ``b``/``e`` span pairs keyed by request id (async
     events overlap cleanly, which batched requests always do), with
     residual requests as instants; discrete :class:`TraceEvent`\\ s are
-    instants on their device's pid-1 track. Timestamps are microseconds.
+    instants on their device's pid-1 track. A live run's phase spans are
+    ``X`` events on a "host loop" thread of pid 1, beside the quanta.
+    Timestamps are microseconds.
     Strict JSON throughout (``allow_nan=False``): Perfetto's parser
     rejects bare ``NaN`` literals.
     """
@@ -635,6 +699,17 @@ def export_chrome_trace(trace: Trace, path: str) -> str:
                    "ts": 0, "args": {"name": f"device {d}"}})
         ev.append({"ph": "M", "name": "thread_name", "pid": 2, "tid": d,
                    "ts": 0, "args": {"name": f"device {d} requests"}})
+    if trace.phases:
+        host = devices[-1] + 1
+        ev.append({"ph": "M", "name": "thread_name", "pid": 1, "tid": host,
+                   "ts": 0, "args": {"name": "host loop"}})
+        for p in trace.phases:
+            ev.append({
+                "ph": "X", "pid": 1, "tid": host, "cat": "phase",
+                "name": p.name, "ts": p.start * us,
+                "dur": max((p.end - p.start) * us, 0.0),
+                "args": {"round": p.round, "quantum": p.quantum},
+            })
     for r in trace.decisions:
         ev.append({
             "ph": "X", "pid": 1, "tid": r.device, "cat": "quantum",

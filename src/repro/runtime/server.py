@@ -33,7 +33,7 @@ from repro.core.profile import ProfileTable
 from repro.core.queues import QueueSnapshot, ServiceQueue
 from repro.core.request import Completion, Request
 from repro.core.scheduler import Scheduler
-from repro.core.telemetry import Tracer, decision_margin
+from repro.core.telemetry import Tracer
 
 
 @dataclasses.dataclass
@@ -51,6 +51,9 @@ class ServedModel:
       num_exits:  number of early-exit heads, shallowest -> deepest.
       executables: ``(exit, batch) -> compiled executable``, filled on
                   first use by :meth:`execute`.
+      phase_hook: set by a traced :meth:`ServingEngine.run` while it lasts,
+                  else ``None``: called with ``"compile"``, ``"launch"``
+                  and ``"wait"`` as :meth:`execute` enters each phase.
     """
 
     name: str
@@ -60,6 +63,8 @@ class ServedModel:
     num_exits: int
     executables: Dict[Tuple[int, int], Any] = dataclasses.field(
         default_factory=dict, init=False, repr=False, compare=False)
+    phase_hook: Optional[Callable[[str], None]] = dataclasses.field(
+        default=None, init=False, repr=False, compare=False)
 
     def execute(self, e: int, b: int):
         """One quantum: exit ``e`` at batch ``b``, blocked until done.
@@ -68,14 +73,52 @@ class ServedModel:
         calls reuse that executable, which refuses inputs of another shape
         rather than recompiling behind the caller's back.
         """
+        hook = self.phase_hook
         x = self.data_fn(b)
         fn = self.executables.get((e, b))
         if fn is None:
+            if hook is not None:
+                hook("compile")
             fn = jax.jit(
                 lambda v, x, _e=e: self.forward_fn(v, x, _e)
             ).lower(self.values, x).compile()
             self.executables[(e, b)] = fn
-        return jax.block_until_ready(fn(self.values, x))
+        if hook is not None:
+            hook("launch")
+        out = fn(self.values, x)
+        if hook is not None:
+            hook("wait")
+        return jax.block_until_ready(out)
+
+
+class _ExecuteMarks:
+    """A traced run's :attr:`ServedModel.phase_hook`: stamps each phase
+    boundary inside ``execute`` on the engine's clock, and opens the
+    profiler annotation ``quantum#<quantum>`` at ``launch``, which
+    :meth:`take` closes once the quantum is done."""
+
+    def __init__(self, clock: Callable[[], float], t0: float):
+        self.clock = clock
+        self.t0 = t0
+        self.quantum = 0
+        self._marks: List[Tuple[str, float]] = []
+        self._annotation = None
+
+    def __call__(self, name: str) -> None:
+        if name == "launch":
+            self._annotation = jax.profiler.TraceAnnotation(
+                f"quantum#{self.quantum}")
+            self._annotation.__enter__()
+        self._marks.append((name, self.clock() - self.t0))
+
+    def take(self) -> List[Tuple[str, float]]:
+        """Close the annotation; return the quantum's marks and forget
+        them."""
+        if self._annotation is not None:
+            self._annotation.__exit__(None, None, None)
+            self._annotation = None
+        marks, self._marks = self._marks, []
+        return marks
 
 
 def measure_profile(
@@ -215,6 +258,22 @@ class ServingEngine:
         With a ``profiler`` attached, each quantum's measured wall-clock
         service feeds ``OnlineProfiler.observe`` and the scheduler's table
         is refreshed in place on the profiler's cadence.
+
+        With a ``tracer`` attached, every round also records its phases
+        (``telemetry.PhaseSpan``), which tile ``[0, t_exit]``: each
+        boundary is one clock read that ends one phase and starts the
+        next, and ``t_dispatch``/``t_done`` are the ``input`` start and the
+        ``wait`` end. The tracer's own records of a quantum are its last
+        phase, ``trace``, so the instrument's cost shows by name and stays
+        out of the phases it measures. A round that starts with every
+        queue empty cannot dispatch and reads the clock once. Each
+        quantum's launch and wait run inside the profiler annotation
+        ``quantum#<k>``, ``k`` being its index in ``trace.decisions``,
+        which puts a device trace on this clock. The live engine records
+        no decision margin: re-scoring every candidate would double the
+        scheduler's cost in the run that measures it. Without a tracer
+        the loop reads the clock three times per dispatching round and
+        once per idle round.
         """
         t0 = self.clock()
         next_arr = 0
@@ -222,80 +281,121 @@ class ServingEngine:
         self._unsubmitted = 0
         tracer = self.tracer
         slo = self.scheduler.config.slo
-        while True:
-            now = self.clock() - t0
-            while next_arr < n and arrivals[next_arr].arrival <= now:
-                self.submit(arrivals[next_arr])
-                next_arr += 1
-            if now > duration + drain_cap:
-                # stranded work stays queued; the never-ingested trace tail
-                # is counted too so completions + dropped + residual still
-                # equals the arrival count (mirrors the simulator).
-                self._unsubmitted = n - next_arr
-                break
-            if now > duration and next_arr >= n:
-                if not drain or all(len(q) == 0 for q in self.queues):
+        if tracer is not None:
+            marks = _ExecuteMarks(self.clock, t0)
+            rnd = -1
+            for mod in self.models:
+                mod.phase_hook = marks
+        try:
+            while True:
+                now = self.clock() - t0
+                if tracer is not None:
+                    rnd += 1
+                    t_round = now if rnd else 0.0
+                while next_arr < n and arrivals[next_arr].arrival <= now:
+                    self.submit(arrivals[next_arr])
+                    next_arr += 1
+                if now > duration + drain_cap:
+                    # stranded work stays queued; the never-ingested trace
+                    # tail is counted too so completions + dropped +
+                    # residual still equals the arrival count (mirrors the
+                    # simulator).
+                    self._unsubmitted = n - next_arr
                     break
-            snapshot = QueueSnapshot.take(self.queues, now)
-            for m, cnt in self.scheduler.prune(snapshot):
-                popped = self.queues[m].pop_batch(cnt)
-                n_shed = len(popped)
-                self.dropped += n_shed
-                self.counters["dropped"] += n_shed
-                if tracer is not None:
-                    for req in popped:
-                        tracer.record_drop(req, now, slo)
-                    if n_shed:
-                        tracer.record_event(now, "shed", n=n_shed)
-                if self.profiler is not None:
-                    self.profiler.observe_dropped(n_shed)
-            decision = self.scheduler.decide(snapshot)
-            if decision is None:
-                self.counters["stalls"] += 1
-                time.sleep(idle_sleep)
-                continue
-            batch = self.queues[decision.model].pop_batch(decision.batch_size)
-            t_dispatch = self.clock() - t0
-            self.models[decision.model].execute(decision.exit_idx,
-                                                decision.batch_size)
-            t_done = self.clock() - t0
-            self._busy_s += t_done - t_dispatch
-            self.counters["batches_served"] += 1
-            self.counters["requests_served"] += len(batch)
-            if tracer is not None:
-                tracer.record_decision(
-                    t_dispatch, decision, t_done,
-                    tuple(snapshot.qlens()),
-                    tuple(snapshot.w_max(m)
-                          for m in range(len(self.queues))),
-                    margin=decision_margin(self.scheduler, snapshot),
-                )
-            for req in batch:
-                self.completions.append(Completion(
-                    req_id=req.req_id, model=req.model, arrival=req.arrival,
-                    dispatch=t_dispatch, finish=t_done,
-                    exit_idx=decision.exit_idx,
-                    batch_size=decision.batch_size,
-                    deadline=req.deadline,
-                ))
-                if tracer is not None:
-                    tracer.record_completion(
-                        req, t_dispatch, t_done, decision.exit_idx,
-                        decision.batch_size, slo)
-            if self.profiler is not None:
-                refreshed = self.profiler.ingest_quantum(
-                    decision.model, decision.exit_idx, decision.batch_size,
-                    t_done - t_dispatch, t_done, batch,
-                    self.scheduler.config.slo)
-                if refreshed is not None:
-                    self.scheduler.table = refreshed
-                    self.counters["profiler_refreshes"] += 1
+                if now > duration and next_arr >= n:
+                    if not drain or all(len(q) == 0 for q in self.queues):
+                        break
+                # a round that starts with every queue empty cannot
+                # dispatch, so it takes no phase stamps: it joins a poll
+                stamp = tracer is not None and any(self.queues)
+                if stamp:
+                    t_snapshot = self.clock() - t0
+                snapshot = QueueSnapshot.take(self.queues, now)
+                if stamp:
+                    t_prune = self.clock() - t0
+                for m, cnt in self.scheduler.prune(snapshot):
+                    popped = self.queues[m].pop_batch(cnt)
+                    n_shed = len(popped)
+                    self.dropped += n_shed
+                    self.counters["dropped"] += n_shed
                     if tracer is not None:
+                        for req in popped:
+                            tracer.record_drop(req, now, slo)
+                        if n_shed:
+                            tracer.record_event(now, "shed", n=n_shed)
+                    if self.profiler is not None:
+                        self.profiler.observe_dropped(n_shed)
+                if stamp:
+                    t_decide = self.clock() - t0
+                decision = self.scheduler.decide(snapshot)
+                if stamp:
+                    t_pop = self.clock() - t0
+                if decision is None:
+                    if tracer is not None:
+                        tracer.record_phase(t_round, rnd, -1, "poll")
+                    self.counters["stalls"] += 1
+                    time.sleep(idle_sleep)
+                    continue
+                batch = self.queues[decision.model].pop_batch(
+                    decision.batch_size)
+                if tracer is not None:
+                    q = marks.quantum = len(tracer.decisions)
+                t_dispatch = self.clock() - t0
+                self.models[decision.model].execute(decision.exit_idx,
+                                                    decision.batch_size)
+                t_done = self.clock() - t0
+                self._busy_s += t_done - t_dispatch
+                self.counters["batches_served"] += 1
+                self.counters["requests_served"] += len(batch)
+                for req in batch:
+                    self.completions.append(Completion(
+                        req_id=req.req_id, model=req.model,
+                        arrival=req.arrival, dispatch=t_dispatch,
+                        finish=t_done, exit_idx=decision.exit_idx,
+                        batch_size=decision.batch_size,
+                        deadline=req.deadline,
+                    ))
+                refreshed = None
+                if self.profiler is not None:
+                    refreshed = self.profiler.ingest_quantum(
+                        decision.model, decision.exit_idx,
+                        decision.batch_size, t_done - t_dispatch, t_done,
+                        batch, self.scheduler.config.slo)
+                    if refreshed is not None:
+                        self.scheduler.table = refreshed
+                        self.counters["profiler_refreshes"] += 1
+                if tracer is not None:
+                    t_trace = self.clock() - t0
+                    for name, t in (("ingest", t_round),
+                                    ("snapshot", t_snapshot),
+                                    ("prune", t_prune),
+                                    ("decide", t_decide), ("pop", t_pop),
+                                    ("input", t_dispatch), *marks.take(),
+                                    ("record", t_done), ("trace", t_trace)):
+                        tracer.record_phase(t, rnd, q, name)
+                    tracer.record_decision(
+                        t_dispatch, decision, t_done,
+                        tuple(snapshot.qlens()),
+                        tuple(snapshot.w_max(m)
+                              for m in range(len(self.queues))),
+                    )
+                    for req in batch:
+                        tracer.record_completion(
+                            req, t_dispatch, t_done, decision.exit_idx,
+                            decision.batch_size, slo)
+                    if refreshed is not None:
                         tracer.record_refresh(t_done, self.profiler)
+        finally:
+            if tracer is not None:
+                marks.take()
+                for mod in self.models:
+                    mod.phase_hook = None
         t_exit = self.clock() - t0
         self.counters["drain_residual"] = (
             sum(len(q) for q in self.queues) + self._unsubmitted)
         if tracer is not None:
+            tracer.record_phase(t_round, rnd, -1, "poll")
+            tracer.close_phase(t_exit)
             tracer.record_event(t_exit, "engine-counters", **self.counters)
         return self.completions, t_exit
 
