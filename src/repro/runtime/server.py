@@ -25,6 +25,7 @@ import time
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 from repro.core.adaptive import OnlineProfiler
@@ -34,6 +35,56 @@ from repro.core.queues import QueueSnapshot, ServiceQueue
 from repro.core.request import Completion, Request
 from repro.core.scheduler import Scheduler
 from repro.core.telemetry import Tracer
+
+
+def pack_values(values) -> Tuple[Tuple[jax.Array, ...], Callable]:
+    """``values`` as few argument buffers for an executable.
+
+    Leaves of rank 0 or 1 (norm scales and biases) that share a (shape,
+    dtype) are stacked into one ``[n, *shape]`` device array, all groups
+    by one jitted call; each leaf of higher rank (convolution kernels,
+    heads) stays its own buffer, as it is. Buffers come in the order of
+    their first leaf in ``values``. Returns ``(packed, unpack)``:
+    ``unpack(packed)``, traced inside an executable, rebuilds the pytree
+    from static rows of the stacks.
+
+    Each call of an executable hands over every argument buffer at a host
+    cost per buffer, so resnet152's 469 weights go as 165 buffers. Matrices
+    are not stacked: XLA reads rows of a stacked matrix in place instead of
+    staging them in on-chip memory, which made resnet152's quanta 9% (B =
+    10) to 39% (B = 1) longer on a TPU v5e.
+    """
+    leaves, treedef = jax.tree_util.tree_flatten(values)
+    buffers: List[Any] = []            # a matrix, or the rows of a stack
+    where: List[Tuple[int, Optional[int]]] = []   # leaf -> (buffer, row)
+    groups: Dict[Tuple[Tuple[int, ...], np.dtype], int] = {}
+    for leaf in leaves:
+        shape = np.shape(leaf)
+        if len(shape) > 1:
+            where.append((len(buffers), None))
+            buffers.append(leaf)
+            continue
+        key = (shape, np.result_type(leaf))
+        if key not in groups:
+            groups[key] = len(buffers)
+            buffers.append([])
+        g = groups[key]
+        where.append((g, len(buffers[g])))
+        buffers[g].append(leaf)
+    stacked = list(groups.values())
+    stacks = jax.jit(lambda rows: [jnp.stack(r) for r in rows])(
+        [buffers[g] for g in stacked])
+    for g, stack in zip(stacked, stacks):
+        buffers[g] = stack
+    packed = tuple(buffers)
+
+    def unpack(packed):
+        return jax.tree_util.tree_unflatten(treedef, [
+            packed[s] if row is None
+            else jax.lax.index_in_dim(packed[s], row, keepdims=False)
+            for s, row in where])
+
+    return packed, unpack
 
 
 @dataclasses.dataclass
@@ -50,7 +101,11 @@ class ServedModel:
                   serving quanta.
       num_exits:  number of early-exit heads, shallowest -> deepest.
       executables: ``(exit, batch) -> compiled executable``, filled on
-                  first use by :meth:`execute`.
+                  first use by :meth:`execute`; each takes ``(packed, x)``.
+      packed:     ``values`` as :func:`pack_values` hands them over, built
+                  by the first :meth:`execute` (``values`` is read once).
+      launch_buffers: device buffers each call hands its executable:
+                  ``len(packed)`` plus the input's leaves (0 until packed).
       phase_hook: set by a traced :meth:`ServingEngine.run` while it lasts,
                   else ``None``: called with ``"compile"``, ``"launch"``
                   and ``"wait"`` as :meth:`execute` enters each phase.
@@ -63,29 +118,42 @@ class ServedModel:
     num_exits: int
     executables: Dict[Tuple[int, int], Any] = dataclasses.field(
         default_factory=dict, init=False, repr=False, compare=False)
+    packed: Optional[Tuple[jax.Array, ...]] = dataclasses.field(
+        default=None, init=False, repr=False, compare=False)
+    launch_buffers: int = dataclasses.field(
+        default=0, init=False, repr=False, compare=False)
+    _unpack: Optional[Callable[[Tuple[jax.Array, ...]], Any]] = (
+        dataclasses.field(default=None, init=False, repr=False,
+                          compare=False))
     phase_hook: Optional[Callable[[str], None]] = dataclasses.field(
         default=None, init=False, repr=False, compare=False)
 
     def execute(self, e: int, b: int):
         """One quantum: exit ``e`` at batch ``b``, blocked until done.
 
-        The first call for an (e, b) cell compiles it ahead of time; later
-        calls reuse that executable, which refuses inputs of another shape
+        The first call packs ``values`` into :attr:`packed`. The first
+        call for an (e, b) cell compiles it ahead of time; later calls
+        reuse that executable, which refuses inputs of another shape
         rather than recompiling behind the caller's back.
         """
         hook = self.phase_hook
         x = self.data_fn(b)
         fn = self.executables.get((e, b))
-        if fn is None:
+        if fn is None or self.packed is None:
             if hook is not None:
                 hook("compile")
-            fn = jax.jit(
-                lambda v, x, _e=e: self.forward_fn(v, x, _e)
-            ).lower(self.values, x).compile()
-            self.executables[(e, b)] = fn
+            if self.packed is None:
+                self.packed, self._unpack = pack_values(self.values)
+                self.launch_buffers = (len(self.packed)
+                                       + len(jax.tree_util.tree_leaves(x)))
+            if fn is None:
+                fn = jax.jit(
+                    lambda p, x, _e=e: self.forward_fn(self._unpack(p), x, _e)
+                ).lower(self.packed, x).compile()
+                self.executables[(e, b)] = fn
         if hook is not None:
             hook("launch")
-        out = fn(self.values, x)
+        out = fn(self.packed, x)
         if hook is not None:
             hook("wait")
         return jax.block_until_ready(out)
@@ -193,7 +261,8 @@ class ServingEngine:
         self._unsubmitted = 0  # trace tail never ingested (drain-cap exit)
         # Structured engine counters, cumulative across run() calls (like
         # the completion log); "engine-counters" trace events snapshot them
-        # at each run() exit. stalls = idle rounds that slept.
+        # at each run() exit. stalls = idle rounds that slept;
+        # launch_buffers = buffers the quanta handed their executables.
         self.counters: Dict[str, int] = {
             "batches_served": 0,
             "requests_served": 0,
@@ -201,6 +270,7 @@ class ServingEngine:
             "profiler_refreshes": 0,
             "dropped": 0,
             "drain_residual": 0,
+            "launch_buffers": 0,
         }
 
     # -- ingress ---------------------------------------------------------------
@@ -341,11 +411,12 @@ class ServingEngine:
                 if tracer is not None:
                     q = marks.quantum = len(tracer.decisions)
                 t_dispatch = self.clock() - t0
-                self.models[decision.model].execute(decision.exit_idx,
-                                                    decision.batch_size)
+                model = self.models[decision.model]
+                model.execute(decision.exit_idx, decision.batch_size)
                 t_done = self.clock() - t0
                 self._busy_s += t_done - t_dispatch
                 self.counters["batches_served"] += 1
+                self.counters["launch_buffers"] += model.launch_buffers
                 self.counters["requests_served"] += len(batch)
                 for req in batch:
                     self.completions.append(Completion(

@@ -91,7 +91,8 @@ class TestTableOps:
             time.sleep(0.001 * (1 + m + e) * (1 + 0.1 * b))
 
         t = ProfileTable.measure(
-            ["m0", "m1"], ["e0", "e1"], [1, 2], run_fn, repeats=3, warmup=1
+            ["m0", "m1"], ["e0", "e1"], [1, 2], run_fn, repeats=9, warmup=1,
+            percentile=50.0,
         )
         assert t.latency.shape == (2, 2, 2)
         assert np.all(t.latency > 0)

@@ -56,8 +56,10 @@ def deployment():
 
 class TestLiveServing:
     def test_measured_profile_is_sane(self, deployment):
+        # the median of many repeats: a p95 of 3 is the worst of 3, which
+        # one stall of a loaded host decides
         table = measure_profile(deployment, batch_sizes=[1, 2, 4],
-                                repeats=3, warmup=1)
+                                repeats=15, warmup=1, percentile=50.0)
         assert table.latency.shape == (3, 2, 3)
         assert np.all(table.latency > 0)
         # deeper exits of the deepest model cost >= its shallowest exit
