@@ -172,18 +172,19 @@ class TraceEvent:
 class PhaseSpan(NamedTuple):
     """One phase of the live engine's host loop, on the engine's clock.
 
-    A dispatching round runs ``ingest``, ``snapshot``, ``prune``,
-    ``decide``, ``pop``, ``input``, ``launch``, ``wait``, ``record`` and
-    ``trace``; ``input``/``launch``/``wait`` split
-    ``ServedModel.execute`` (data, the executable's call until it
-    returns, ``block_until_ready``), a ``compile`` before ``launch`` marks
-    an executable-cache miss, ``record`` is the completion log and the
-    online profiler, and ``trace`` is the tracer's own records of the
-    quantum. A run of consecutive rounds that dispatch nothing, the exit
-    round included, is one ``poll``.
+    Each quantum has ``ingest``, ``snapshot``, ``prune``, ``decide``,
+    ``pop``, ``input`` and ``launch`` in the round that launches it, then
+    ``wait``, then ``record`` and ``trace`` in the round that sees it
+    done. ``input``/``launch`` split ``ServedModel.execute`` (data, the
+    executable's call until it returns), a ``compile`` before ``launch``
+    marks an executable-cache miss, ``wait`` is the rounds until the
+    quantum is seen done, ``record`` is the completion log and the online
+    profiler, and ``trace`` is the tracer's own records of the quantum. A
+    run of consecutive rounds that launch and complete nothing is the
+    ``wait`` of the quantum whose completion ends it, else one ``poll``.
 
-    ``round`` is the loop iteration the phase belongs to (for ``poll``,
-    the first idle round it covers); ``quantum`` indexes
+    ``round`` is the loop iteration the phase belongs to (for ``wait`` and
+    ``poll``, the first round it covers); ``quantum`` indexes
     ``Trace.decisions`` (-1 for ``poll``). A tuple rather than a frozen
     dataclass: a traced round records ten or more of them, and this is the
     cheapest immutable record.

@@ -500,16 +500,22 @@ class TestEnginePhases:
         assert all(p.end >= p.start for p in ph)
 
     def test_dispatching_rounds_run_nine_phases_in_order(self, table):
-        # the loop's nine phases, then the tracer's own ``trace``
+        # each quantum's nine loop phases, then the tracer's own ``trace``:
+        # the launching round's seven, the quantum's ``wait`` (the rounds
+        # that launch and complete nothing until it is seen done), then the
+        # completing round's ``record`` and ``trace``. Another quantum's
+        # phases may fall between its ``launch`` and its ``wait`` when it
+        # was launched while this one ran.
         eng, trace, _ = self._traced(table)
-        rounds = {}
+        quanta, rounds = {}, {}
         for p in trace.phases:
             if p.quantum >= 0:
-                rounds.setdefault((p.round, p.quantum), []).append(p.name)
-        assert [q for _, q in rounds] == list(range(len(trace.decisions)))
-        assert len(rounds) == eng.counters["batches_served"]
+                quanta.setdefault(p.quantum, []).append(p.name)
+                rounds.setdefault(p.quantum, []).append(p.round)
+        assert list(quanta) == list(range(len(trace.decisions)))
+        assert len(quanta) == eng.counters["batches_served"]
         seen = set()
-        for (_, q), names in rounds.items():
+        for q, names in quanta.items():
             r = trace.decisions[q]
             miss = (r.exit_idx, r.batch_size) not in seen
             seen.add((r.exit_idx, r.batch_size))
@@ -517,6 +523,10 @@ class TestEnginePhases:
             if miss:  # the executable is built on first use: compile
                 want.insert(want.index("launch"), "compile")
             assert names == want
+            at = dict(zip(names, rounds[q]))
+            launching = rounds[q][:names.index("wait")]
+            assert set(launching) == {at["ingest"]}
+            assert at["ingest"] < at["wait"] <= at["record"] == at["trace"]
 
     def test_consecutive_idle_rounds_make_one_poll(self, table):
         eng, trace, _ = self._traced(table)
@@ -525,14 +535,17 @@ class TestEnginePhases:
         assert len(polls) >= 2          # the quiet stretch and the exit
         assert all(ph[i].quantum == -1 for i in polls)
         assert all(ph[i + 1].name != "poll" for i in polls[:-1])
-        # each poll covers the rounds up to the next span's; the last one
-        # runs to the exit round, so the polls cover every stall and it
-        last = eng.counters["batches_served"] + eng.counters["stalls"]
+        # each poll or wait covers the rounds from its own up to the next
+        # span's; the last poll runs to the exit round, so together they
+        # cover every round that launched and completed nothing, and it
+        c = eng.counters
+        last = 2 * c["batches_served"] + c["stalls"]
+        idle = [i for i, p in enumerate(ph) if p.name in ("poll", "wait")]
         ends = [ph[i + 1].round if i + 1 < len(ph) else last + 1
-                for i in polls]
-        covered = [e - ph[i].round for i, e in zip(polls, ends)]
-        assert max(covered) >= 2
-        assert sum(covered) == eng.counters["stalls"] + 1
+                for i in idle]
+        covered = {i: e - ph[i].round for i, e in zip(idle, ends)}
+        assert max(covered[i] for i in polls) >= 2
+        assert sum(covered.values()) == c["stalls"] + 1
 
     def test_untraced_loop_reads_the_clock_three_times_per_quantum(
             self, table):
